@@ -8,10 +8,9 @@
 # end to end), a gated nocsim coverage floor, and the resumable dry-run
 # artifact sweep.
 #
-# The whole suite gates: the last 5 seed failures (roofline HLO parse,
-# elastic reshard restore, the 3 multi-device subprocess meshes) were fixed
-# by the jax-0.4 compat shims (src/repro/compat.py), so there is no
-# "pre-existing failures" carve-out any more.  Property tests never skip:
+# The whole suite gates; there is no "pre-existing failures" carve-out.
+# Tests run on the CPU; the chip is reached by running `python chip_smoke.py`
+# on a TPU host.  Property tests never skip:
 # tests/_hypothesis_compat.py vendors a minimal fallback runner when the
 # offline container has no hypothesis wheel.
 set -euo pipefail
@@ -28,39 +27,8 @@ else
     echo "property tests run on the vendored fallback (tests/_hypothesis_compat.py)"
 fi
 
-echo "== gating tests (full tier-1 suite) =="
-python -m pytest -x -q
-
-echo "== jax >= 0.5 native-API arm (compat shims force-disabled) =="
-# ROADMAP jax-version matrix: when the installed jax already provides the
-# 0.5 surface natively (AxisType / set_mesh / shard_map / make_mesh
-# axis_types), re-run a fast smoke subset with install_jax05_compat()
-# force-disabled so the no-op branch of every shim is exercised against the
-# real APIs.  On the pinned 0.4 container the arm is skipped — there the
-# shims themselves are what the full suite above just exercised — keeping
-# both branches honest whichever jax the image ships.
-if python - <<'EOF'
-import inspect, sys
-try:
-    import jax
-except ImportError:
-    sys.exit(1)
-native = (
-    hasattr(jax, "set_mesh")
-    and hasattr(jax, "shard_map")
-    and hasattr(jax.sharding, "AxisType")
-    and "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
-sys.exit(0 if native else 1)
-EOF
-then
-    REPRO_DISABLE_JAX05_COMPAT=1 python -m pytest -q \
-        tests/test_nocsim.py tests/test_simulator_and_traffic.py \
-        tests/test_placement_batch.py tests/test_models.py
-else
-    echo "installed jax lacks the native 0.5 surface; smoke arm skipped"
-    echo "(the 0.4->0.5 shims were exercised by the full suite above)"
-fi
+echo "== gating tests (full tier-1 suite, on the CPU) =="
+JAX_PLATFORMS=cpu python -m pytest -x -q
 
 echo "== scale memory budget (sparse pipeline @ soc-pokec scale 0.1) =="
 # The published-size pipeline guard: one scale-0.1 soc-pokec sweep (3.06M

@@ -94,11 +94,7 @@ class DryrunCase:
     note: str = ""
 
     def lower(self, mesh):
-        # jax.set_mesh is ≥ 0.5; on older jax a Mesh is its own context
-        # manager, which sets the ambient physical mesh that
-        # repro.models.sharding.active_mesh() (and the shard_map paths) read.
-        ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-        with ctx:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 self.fn, in_shardings=self.in_shardings, donate_argnums=self.donate_argnums
             )

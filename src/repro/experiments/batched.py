@@ -23,8 +23,9 @@ fallback for other topologies — so batched results equal the serial ones to
 fp tolerance (tested in tests/test_experiments_sweep.py).
 
 Backends: "numpy" (float64, bit-exact vs serial up to summation order) and
-"jax" (`jax.jit`-compiled contractions; float32 on CPU by default, ~1e-6
-relative).  "auto" picks jax when importable, else numpy.
+"jax" (`jax.jit`-compiled float32 contractions at
+`repro.precision.DOT_PRECISION`, ≤ 1e-6 relative on CPU and TPU).  "auto"
+picks jax past `JAX_AUTO_THRESHOLD` stacked elements, else numpy.
 """
 from __future__ import annotations
 
@@ -35,6 +36,7 @@ from repro.core.noc import Topology
 from repro.core.placement import Placement
 from repro.core.simulator import SimParams, SimResult
 from repro.core.traffic import SparseTraffic, TrafficMatrix
+from repro.precision import DOT_PRECISION
 
 __all__ = [
     "routing_operator",
@@ -208,8 +210,35 @@ _JAX_KERNELS: dict[bool, object] = {}
 _JAX_DENSE_ROUTING: dict[int, object] = {}
 
 
-def _contract_jax(stack: np.ndarray, dist: np.ndarray, routing):
+def _jax_contract_fn(with_routing: bool):
+    """Build (once per arm) the jitted stacked contractions; jit
+    re-specialises per (C, N, L) group shape automatically."""
+    kernel = _JAX_KERNELS.get(with_routing)
+    if kernel is not None:
+        return kernel
     import jax
+    import jax.numpy as jnp
+
+    if with_routing:
+
+        def kernel(B, D, R):
+            total = B.sum(axis=(1, 2))
+            bh = jnp.einsum("cst,st->c", B, D, precision=DOT_PRECISION)
+            loads = jnp.matmul(B.reshape(B.shape[0], -1), R.T, precision=DOT_PRECISION)
+            return total, bh, loads.max(axis=1)
+
+    else:
+
+        def kernel(B, D):
+            total = B.sum(axis=(1, 2))
+            bh = jnp.einsum("cst,st->c", B, D, precision=DOT_PRECISION)
+            return total, bh
+
+    kernel = _JAX_KERNELS[with_routing] = jax.jit(kernel)
+    return kernel
+
+
+def _contract_jax(stack: np.ndarray, dist: np.ndarray, routing):
     import jax.numpy as jnp
 
     with_routing = routing is not None
@@ -218,26 +247,7 @@ def _contract_jax(stack: np.ndarray, dist: np.ndarray, routing):
         if dense is None:
             dense = _JAX_DENSE_ROUTING[id(routing)] = jnp.asarray(routing.toarray())
         routing = dense
-    kernel = _JAX_KERNELS.get(with_routing)
-    if kernel is None:
-
-        if with_routing:
-
-            def kernel(B, D, R):
-                total = B.sum(axis=(1, 2))
-                bh = jnp.einsum("cst,st->c", B, D)
-                loads = B.reshape(B.shape[0], -1) @ R.T
-                return total, bh, loads.max(axis=1)
-
-        else:
-
-            def kernel(B, D):
-                total = B.sum(axis=(1, 2))
-                bh = jnp.einsum("cst,st->c", B, D)
-                return total, bh
-
-        kernel = jax.jit(kernel)
-        _JAX_KERNELS[with_routing] = kernel
+    kernel = _jax_contract_fn(with_routing)
     if with_routing:
         total, bh, peak = kernel(stack, dist.astype(np.float64), routing)
         return np.asarray(total, np.float64), np.asarray(bh, np.float64), np.asarray(peak, np.float64)
@@ -355,6 +365,7 @@ def batched_weighted_hops(
         import jax.numpy as jnp
 
         d = jnp.asarray(dist)[sites[:, :, None], sites[:, None, :]]
-        return np.asarray(jnp.einsum("cij,cij->c", jnp.asarray(weights), d), np.float64)
+        h = jnp.einsum("cij,cij->c", jnp.asarray(weights), d, precision=DOT_PRECISION)
+        return np.asarray(h, np.float64)
     d = dist[sites[:, :, None], sites[:, None, :]]
     return np.einsum("cij,cij->c", weights, d)

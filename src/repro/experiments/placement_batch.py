@@ -54,8 +54,8 @@ differ inside a group (the per-config distance matrices are stacked).
 Backends (via `resolve_backend`, like `simulate_batch`): "numpy" — float64
 einsums, bit-identical to `two_opt_best_move` per config; "jax" —
 `jax.jit`-compiled `jax.lax.while_loop`/`fori_loop`, weights pre-normalised
-per config so float32 on CPU keeps the accept decisions stable (~1e-6
-relative H).
+per config so float32 keeps the accept decisions stable (~1e-6 relative H;
+contractions at `repro.precision.DOT_PRECISION`).
 
 Search quality: steepest descent converges to a local optimum of the same
 swap+move neighbourhood the serial randomized search explores, and on paper-
@@ -90,6 +90,7 @@ from repro.core.placement import (
 from repro.core.traffic import TrafficMatrix
 from repro.analysis.registry import parity_pair
 from repro.experiments.batched import resolve_backend
+from repro.precision import DOT_PRECISION
 
 __all__ = [
     "batch_descend",
@@ -498,9 +499,9 @@ def _jax_pair_deltas_fn():
     def deltas(w, d, site, pi, pj):  # (n,n), (S,S), (n,), (P,), (P,)
         dsite = d[site]  # (n, S)
         dss = dsite[:, site]
-        diag = jnp.einsum("ik,ki->i", w, dss)
-        a_ij = jnp.einsum("pk,kp->p", w[pi], dsite[:, site[pj]])
-        a_ji = jnp.einsum("pk,kp->p", w[pj], dsite[:, site[pi]])
+        diag = jnp.einsum("ik,ki->i", w, dss, precision=DOT_PRECISION)
+        a_ij = jnp.einsum("pk,kp->p", w[pi], dsite[:, site[pj]], precision=DOT_PRECISION)
+        a_ji = jnp.einsum("pk,kp->p", w[pj], dsite[:, site[pi]], precision=DOT_PRECISION)
         dij = d[site[pi], site[pj]]
         return a_ij + a_ji + 2.0 * w[pi, pj] * dij - diag[pi] - diag[pj]
 
@@ -715,11 +716,11 @@ def _jax_descend_fn():
     def step_one(w, d, site, occ, tol):
         n = site.shape[0]
         dss = d[site[:, None], site[None, :]]
-        a = w @ dss
+        a = jnp.matmul(w, dss, precision=DOT_PRECISION)
         diag = jnp.diagonal(a)
         ds = a + a.T + 2.0 * w * dss - diag[:, None] - diag[None, :]
         ds = jnp.where(jnp.eye(n, dtype=bool), jnp.inf, ds)
-        dm = w @ d[:, site].T - diag[:, None]
+        dm = jnp.matmul(w, d[:, site].T, precision=DOT_PRECISION) - diag[:, None]
         dm = jnp.where(occ[None, :], jnp.inf, dm)
         bs = jnp.argmin(ds.reshape(-1))
         bm = jnp.argmin(dm.reshape(-1))
@@ -774,10 +775,10 @@ def _descend_jax(
     np.put_along_axis(occ, sites, True, axis=1)
     if blocked is not None:
         occ |= blocked
-    # Normalise per config so float32 (jax CPU default) keeps accept
-    # decisions stable across the byte-scale range of real traffic; the
-    # accept tolerance is widened accordingly (relative to H ~ O(n) after
-    # normalisation) so f32 rounding noise cannot cycle the descent.
+    # Normalise per config so float32 keeps accept decisions stable across
+    # the byte-scale range of real traffic; the accept tolerance is widened
+    # accordingly (relative to H ~ O(n) after normalisation) so f32 rounding
+    # noise cannot cycle the descent.
     scale = np.maximum(w.reshape(c, -1).max(axis=1), 1.0)[:, None, None]
     out_sites, steps = _jax_descend_fn()(
         jnp.asarray(w / scale),

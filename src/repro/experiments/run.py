@@ -32,6 +32,7 @@ import os
 import signal
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.experiments.grid import GRIDS, grid_by_name
 from repro.experiments.journal import SweepJournal, flush_all_journals
 from repro.experiments.report import (
@@ -184,6 +185,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("-q", "--quiet", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     recorder = None
     if args.trace_out:

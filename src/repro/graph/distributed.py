@@ -212,21 +212,10 @@ class DistributedEngine:
             P("engines"), P("engines"), P(),
         )
         out_specs = (P("engines"), P("engines"), P())
-        # Local copy of repro.models.sharding.compat_shard_map (the graph
-        # layer sits below models and must not import upward): jax ≥ 0.5
-        # spells the replication check `check_vma`, older jax `check_rep`.
-        if hasattr(jax, "shard_map"):
-            mapped = jax.shard_map(
-                local_step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        else:
-            from jax.experimental.shard_map import shard_map
-
-            mapped = shard_map(
-                local_step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False,
-            )
+        mapped = jax.shard_map(
+            local_step, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
+        )
         return jax.jit(mapped)
 
     def run(

@@ -13,15 +13,18 @@ are harmless; messages from inactive edges carry the reduce identity.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import typing
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.graph.structs import EdgeList, HostGraph, to_device_edges
+from repro.graph.structs import HostGraph, to_device_edges
 
-__all__ = ["VertexProgram", "RunResult", "TraceResult", "run", "run_traced"]
+__all__ = [
+    "VertexProgram", "RunResult", "TraceResult", "run", "run_traced", "run_loop", "traced_step",
+]
 
 Array = jnp.ndarray
 
@@ -79,16 +82,17 @@ class TraceResult:
 
 def _one_iteration(
     program: VertexProgram,
-    edges: EdgeList,
+    graph: tuple[Array, Array, Array, Array | None],
     props: Array,
     active: Array,
     aux: dict,
 ) -> tuple[Array, Array, Array]:
-    """Returns (new_props, new_active, edge_active)."""
+    """Returns (new_props, new_active, edge_active).  `graph` is the device
+    edge list (src, dst, valid, weight-or-None) of an `EdgeList`."""
+    src, dst, valid, weight = graph
     n_sentinel = props.shape[0]  # N + 1
-    src, dst = edges.src, edges.dst
-    w = edges.weight if edges.weight is not None else jnp.ones(src.shape[0], jnp.float32)
-    edge_active = active[src] & edges.valid
+    w = weight if weight is not None else jnp.ones(src.shape[0], jnp.float32)
+    edge_active = active[src] & valid
     msg = program.process(props[src], w, aux)
     msg = jnp.where(edge_active, msg, jnp.asarray(program.identity, msg.dtype))
     temp = program.segment_reduce(msg, dst, n_sentinel)
@@ -102,6 +106,42 @@ def _one_iteration(
     return new_props, new_active, edge_active
 
 
+# The graph and `aux` enter both compiled programs as arguments, never as
+# closure constants: a baked-in edge list makes the program as large as the
+# graph and gives it a compile-cache key that changes with every graph.  One
+# executable serves every graph of a given shape.
+traced_step = jax.jit(_one_iteration, static_argnums=0)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def run_loop(program: VertexProgram, max_iterations: int, graph, props, active, aux):
+    """`lax.while_loop` over `_one_iteration` until the frontier empties
+    ("delta") or the L1 change drops to `program.tol` ("all")."""
+
+    def cond(state):
+        _, active, it, delta = state
+        if program.frontier == "delta":
+            return jnp.any(active) & (it < max_iterations)
+        return (delta > program.tol) & (it < max_iterations)
+
+    def body(state):
+        props, active, it, _ = state
+        new_props, new_active, _ = _one_iteration(program, graph, props, active, aux)
+        delta = jnp.sum(jnp.abs(jnp.nan_to_num(new_props - props, posinf=0.0)))
+        return new_props, new_active, it + 1, delta
+
+    return jax.lax.while_loop(
+        cond, body, (props, active, jnp.asarray(0), jnp.asarray(jnp.inf, props.dtype))
+    )
+
+
+def _device_graph(g: HostGraph, program: VertexProgram, pad_to: int | None):
+    edges = to_device_edges(g, pad_to=pad_to)
+    graph = (edges.src, edges.dst, edges.valid, edges.weight)
+    aux = {k: jnp.asarray(v) for k, v in program.make_aux(g).items()}
+    return graph, aux
+
+
 def run(
     g: HostGraph,
     program: VertexProgram,
@@ -111,29 +151,9 @@ def run(
     pad_to: int | None = None,
 ) -> RunResult:
     """Jitted execution with lax.while_loop until frontier-empty/converged."""
-    edges = to_device_edges(g, pad_to=pad_to)
+    graph, aux = _device_graph(g, program, pad_to)
     props0, active0 = program.init(g.num_nodes, source)
-    aux = {k: jnp.asarray(v) for k, v in program.make_aux(g).items()}
-
-    def cond(state):
-        props, active, it, delta = state
-        not_done = (
-            jnp.any(active) & (it < max_iterations)
-            if program.frontier == "delta"
-            else (delta > program.tol) & (it < max_iterations)
-        )
-        return not_done
-
-    def body(state):
-        props, active, it, _ = state
-        new_props, new_active, _ = _one_iteration(program, edges, props, active, aux)
-        delta = jnp.sum(jnp.abs(jnp.nan_to_num(new_props - props, posinf=0.0)))
-        return new_props, new_active, it + 1, delta
-
-    init = (props0, active0, jnp.asarray(0), jnp.asarray(jnp.inf))
-    props, _, it, _ = jax.jit(
-        lambda s: jax.lax.while_loop(cond, body, s)
-    )(init)
+    props, _, it, _ = run_loop(program, max_iterations, graph, props0, active0, aux)
     return RunResult(np.asarray(props[:-1]), int(it))
 
 
@@ -147,10 +167,8 @@ def run_traced(
 ) -> TraceResult:
     """Python-loop execution that records the communication trace
     (per-edge/vertex activity) for the NoC simulator."""
-    edges = to_device_edges(g, pad_to=pad_to)
+    graph, aux = _device_graph(g, program, pad_to)
     props, active = program.init(g.num_nodes, source)
-    aux = {k: jnp.asarray(v) for k, v in program.make_aux(g).items()}
-    step = jax.jit(lambda p, a: _one_iteration(program, edges, p, a, aux))
 
     e_real = g.num_edges
     edge_activity = np.zeros(e_real, dtype=np.float64)
@@ -160,7 +178,7 @@ def run_traced(
     while it < max_iterations:
         if program.frontier == "delta" and not bool(jnp.any(active)):
             break
-        new_props, new_active, edge_active = step(props, active)
+        new_props, new_active, edge_active = traced_step(program, graph, props, active, aux)
         edge_activity += np.asarray(edge_active)[:e_real]
         changed = np.asarray(new_props != props)[:-1]
         vertex_activity += changed

@@ -7,15 +7,20 @@ Algorithm 2's sort, rows with similar degree share a bucket of fixed width
 W, so the kernel sees a regular (R × W) neighbour grid:
 
   grid (R, W) — neighbour slot j innermost.  The *scalar-prefetched* column
-  ids let the x BlockSpec's index_map name the exact HBM row to DMA for
-  step (i, j); the (1, D) accumulator scratch carries the row's partial sum
-  across the W steps and the output row is written once at j = W-1.
+  ids let the x BlockSpec's index_map name the HBM row group to DMA for
+  step (i, j); the accumulator scratch carries partial sums across the W
+  steps and the output rows are written once per group.
 
-HBM traffic = (#valid edges + padding) × D — the ELL fill fraction (≈0.8 on
-power-law graphs after the degree sort, measured by EllBlocks.fill_fraction)
-is the only overhead over the information-theoretic gather floor.
+Blocks are 8 rows tall (the TPU's sublane tile): a step fetches the 8-row
+group holding its source row and selects the row with a mask, and 8
+consecutive output rows share one (8, D) output block.  Masks rather than
+dynamic indices do the selecting, because Mosaic cannot index a tile's
+sublane or lane dimension with a runtime scalar.
 
-D should be lane-aligned (×128); ops.py pads narrow feature dims.
+HBM traffic = 8 × (#valid edges + padding) × D — the ELL fill fraction
+(≈0.8 on power-law graphs after the degree sort, measured by
+EllBlocks.fill_fraction) and the row-group granularity are the overheads
+over the information-theoretic gather floor.
 """
 from __future__ import annotations
 
@@ -28,23 +33,38 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ell_spmm_pallas"]
 
+ROWS = 8  # sublane tile height: every block's row count
 
-def _spmm_kernel(cols_ref, x_ref, w_ref, o_ref, acc_ref, *, num_nodes: int, width: int):
+
+def _spmm_kernel(cols_ref, x_ref, w_ref, o_ref, acc_ref, *, num_nodes: int, num_rows: int):
     i = pl.program_id(0)
     j = pl.program_id(1)
+    width = pl.num_programs(1)
+    r = i % ROWS
 
-    @pl.when(j == 0)
+    @pl.when((r == 0) & (j == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     col = cols_ref[i, j]
-    valid = col < num_nodes
-    w = w_ref[0, j] * valid.astype(jnp.float32)
-    acc_ref[...] += x_ref[0].astype(jnp.float32) * w
+    src_rows = jax.lax.broadcasted_iota(jnp.int32, x_ref.shape, 0)
+    x_row = jnp.sum(
+        jnp.where(src_rows == jnp.minimum(col, num_nodes - 1) % ROWS, x_ref[...], 0.0),
+        axis=0,
+        keepdims=True,
+    ).astype(jnp.float32)  # (1, D)
+    w_rows = jax.lax.broadcasted_iota(jnp.int32, w_ref.shape, 0)
+    w_lanes = jax.lax.broadcasted_iota(jnp.int32, w_ref.shape, 1)
+    w = jnp.sum(
+        jnp.where((w_rows == r) & (w_lanes == j), w_ref[...], 0.0), keepdims=True
+    )  # (1, 1)
+    w = w * (col < num_nodes).astype(jnp.float32)
+    out_rows = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
+    acc_ref[...] += jnp.where(out_rows == r, x_row * w, 0.0)
 
-    @pl.when(j == width - 1)
+    @pl.when((j == width - 1) & ((r == ROWS - 1) | (i == num_rows - 1)))
     def _finalize():
-        o_ref[0] = acc_ref[0].astype(o_ref.dtype)
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -60,16 +80,19 @@ def ell_spmm_pallas(
     r, w = cols.shape
     if wts is None:
         wts = jnp.ones((r, w), jnp.float32)
-    kernel = functools.partial(_spmm_kernel, num_nodes=n, width=w)
+    kernel = functools.partial(_spmm_kernel, num_nodes=n, num_rows=r)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # cols in SMEM, visible to the x index_map
         grid=(r, w),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, cols_ref: (jnp.minimum(cols_ref[i, j], n - 1), 0)),
-            pl.BlockSpec((1, w), lambda i, j, cols_ref: (i, 0)),
+            pl.BlockSpec(
+                (ROWS, d),
+                lambda i, j, cols_ref: (jnp.minimum(cols_ref[i, j], n - 1) // ROWS, 0),
+            ),
+            pl.BlockSpec((ROWS, w), lambda i, j, cols_ref: (i // ROWS, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i, j, cols_ref: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
+        out_specs=pl.BlockSpec((ROWS, d), lambda i, j, cols_ref: (i // ROWS, 0)),
+        scratch_shapes=[pltpu.VMEM((ROWS, d), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,

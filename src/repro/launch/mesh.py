@@ -21,34 +21,24 @@ import numpy as np
 __all__ = ["make_production_mesh", "make_smoke_mesh", "mesh_devices"]
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """`axis_types` where the installed jax has it (≥ 0.5); empty kwargs on
-    older jax, whose meshes are Auto-typed already — keeps the dry-run
-    runnable on the pinned container jax."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False, device_permutation=None):
     import jax
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType, Mesh
 
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     if device_permutation is None:
-        return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
     devices = np.asarray(jax.devices())[np.asarray(device_permutation)].reshape(shape)
-    return Mesh(devices, axes, **_axis_type_kwargs(len(axes)))
+    return Mesh(devices, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_smoke_mesh(shape=(1, 1), axes=("data", "model")):
     """Single-device mesh for CPU tests (same code path, trivial axes)."""
     import jax
+    from jax.sharding import AxisType
 
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_devices(mesh) -> int:

@@ -24,7 +24,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.graph.halo import HaloPlan, halo_extend
 from repro.models.gnn import GnnConfig, _mlp_apply
-from repro.models.sharding import compat_shard_map
 
 __all__ = ["gin_halo_loss_fn", "gin_forward_halo", "batch_specs_halo"]
 
@@ -59,7 +58,7 @@ def gin_forward_halo(params, batch, cfg: GnnConfig, mesh):
         return body(x[0], send_idx[0], src_slot[0], dst_slot[0], node_ok[0])[None]
 
     sharded = P(axis)
-    return compat_shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(sharded, sharded, sharded, sharded, sharded),

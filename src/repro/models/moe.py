@@ -32,7 +32,6 @@ from repro.models.sharding import (
     MeshRules,
     active_mesh,
     axis_if_divisible,
-    compat_shard_map,
     constrain,
 )
 
@@ -260,7 +259,7 @@ def _moe_ep(m: MoEConfig, lp: dict, x: Array, r: MeshRules) -> Array:
     if n_tok_pad != n_tok:
         x = jnp.pad(x, ((0, n_tok_pad - n_tok), (0, 0)))
     body = functools.partial(_moe_ep_local_body, m, ep, e_pad)
-    out = compat_shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

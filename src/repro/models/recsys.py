@@ -178,7 +178,7 @@ def _lookup_psum_model(cfg: DcnConfig, tables: Array, ids: Array,
         ww = ok.astype(tab_l.dtype) * w_l.astype(tab_l.dtype)
         return jax.lax.psum((rows * ww[..., None]).sum(2), "model")
 
-    return sharding_lib.compat_shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, "model", None), ids_spec, ids_spec),

@@ -19,14 +19,14 @@ import dataclasses
 import jax
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["MeshRules", "constrain", "axis_if_divisible", "active_mesh", "compat_shard_map"]
+__all__ = ["MeshRules", "constrain", "axis_if_divisible", "active_mesh"]
 
 
 def axis_if_divisible(dim: int, axis: str | tuple[str, ...] | None, mesh=None):
     """Return `axis` if `dim` divides evenly over it on the active mesh."""
     if axis is None:
         return None
-    mesh = mesh or _active_mesh()
+    mesh = mesh or active_mesh()
     if mesh is None:
         return axis
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
@@ -38,42 +38,15 @@ def axis_if_divisible(dim: int, axis: str | tuple[str, ...] | None, mesh=None):
     return axis if dim % size == 0 else None
 
 
-def _active_mesh():
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is not None:  # jax ≥ 0.5; fall through on older jax
-        m = get_abstract_mesh()
-        if m is not None and m.shape:
-            return m
-    try:
-        from jax.interpreters.pxla import thread_resources
-
-        env_mesh = thread_resources.env.physical_mesh
-        return env_mesh if env_mesh.devices.size > 1 or env_mesh.axis_names else None
-    except Exception:
-        return None
-
-
 def active_mesh():
-    """The ambient mesh (abstract on jax ≥ 0.5, physical `with mesh:` context
-    on older jax), or None outside any mesh context."""
-    return _active_mesh()
-
-
-def compat_shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` on jax ≥ 0.5; `jax.experimental.shard_map` (where the
-    replication-check kwarg is spelled `check_rep`) on the pinned container
-    jax.  One shim so every shard_map call site stays version-agnostic."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma)
+    """The ambient mesh set by `jax.set_mesh`, or None outside any mesh."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.shape else None
 
 
 def constrain(x, *spec):
     """with_sharding_constraint that degrades to identity with no mesh."""
-    mesh = _active_mesh()
+    mesh = active_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     # drop axis names the mesh doesn't have (e.g. "pod" on single-pod)

@@ -79,6 +79,7 @@ import numpy as np
 
 from repro.nocsim.batch import run_windows
 from repro.nocsim.model import ConfigSchedule, NocSimParams, normalize_buffer_depth
+from repro.precision import DOT_PRECISION
 
 __all__ = [
     "CreditProgram",
@@ -263,7 +264,7 @@ def _jax_credit_fn():
             src, buf = carry
             inj_w, offered_w = x
             demand = src + offered_w
-            demand_link = jnp.einsum("clf,cf->cl", inc, demand)
+            demand_link = jnp.einsum("clf,cf->cl", inc, demand, precision=DOT_PRECISION)
             head = jnp.maximum(depth - buf, 0.0)
             pos = demand_link > 0.0
             ratio = jnp.where(
@@ -279,12 +280,14 @@ def _jax_credit_fn():
             admitted = demand * gate
             src = demand - admitted
             arrivals = jnp.maximum(
-                inj_w + jnp.einsum("clf,cf->cl", inc, admitted - offered_w), 0.0
+                inj_w
+                + jnp.einsum("clf,cf->cl", inc, admitted - offered_w, precision=DOT_PRECISION),
+                0.0,
             )
             arrived = buf + arrivals
             serviced = jnp.minimum(arrived, 1.0)
             buf = arrived - serviced
-            eff = buf + jnp.einsum("clf,cf->cl", inc, src)
+            eff = buf + jnp.einsum("clf,cf->cl", inc, src, precision=DOT_PRECISION)
             return (src, buf), (serviced, eff, buf, src, admitted, arrivals)
 
         (src, buf), tls = jax.lax.scan(body, (src0, buf0), (inj, offered))

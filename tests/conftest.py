@@ -1,18 +1,6 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests see 1 CPU device by
 design (the 512-device flag belongs to launch.dryrun only)."""
-import numpy as np
 import pytest
-
-# The suite is written against the jax ≥ 0.5 surface (AxisType, set_mesh,
-# shard_map); backfill it on the container's jax 0.4 before any test module
-# imports jax (no-op on jax ≥ 0.5; jax-less environments still collect — the
-# jax-dependent tests guard themselves with pytest.importorskip).
-try:
-    from repro.compat import install_jax05_compat
-
-    install_jax05_compat()
-except ImportError:
-    pass
 
 
 @pytest.fixture(scope="session")

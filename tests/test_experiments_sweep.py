@@ -238,6 +238,41 @@ class TestGridAndSweep:
         assert {c.placement for c in cfgs} == {"greedy", "auto", "random"}
         assert sum(c.is_baseline for c in cfgs) == 24
 
+    def test_run_cli_compile_cache_at_fixed_checkout_path(self, monkeypatch):
+        """Without JAX_COMPILATION_CACHE_DIR the sweep CLI keeps JAX's
+        compile cache at artifacts/jax_cache/ of the checkout."""
+        import jax
+
+        from repro.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            assert enable_compile_cache() == CHECKOUT_CACHE_DIR
+            assert jax.config.jax_compilation_cache_dir == CHECKOUT_CACHE_DIR
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        assert CHECKOUT_CACHE_DIR == os.path.join(REPO, "artifacts", "jax_cache")
+
+    def test_compile_cache_env_dir_wins_and_nothing_at_import(self, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR is used as JAX reads it, with nothing
+        else set; importing the entry points configures no cache."""
+        body = (
+            "import jax, chip_smoke, repro.experiments.run\n"
+            "from repro.compile_cache import enable_compile_cache\n"
+            "assert jax.config.jax_compilation_cache_dir == {d!r}\n"
+            "assert enable_compile_cache() == {d!r}\n"
+            "assert jax.config.jax_compilation_cache_dir == {d!r}\n"
+        )
+        env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src") + os.pathsep + REPO)
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        subprocess.run([sys.executable, "-c", body.format(d=str(tmp_path))], env=env,
+                       check=True, timeout=120)
+        env.pop("JAX_COMPILATION_CACHE_DIR")
+        unset = "import jax, chip_smoke, repro.experiments.run\n" \
+                "assert jax.config.jax_compilation_cache_dir is None\n"
+        subprocess.run([sys.executable, "-c", unset], env=env, check=True, timeout=120)
+
     def test_torus_sweep_smoke_through_run_cli(self, tmp_path):
         """Satellite acceptance: `run.py --grid torus --scale 0.001` stores
         the artifact whose §Torus section the paper render consumes."""

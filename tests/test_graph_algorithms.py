@@ -14,7 +14,7 @@ from repro.graph.algorithms import (
 from repro.graph.generators import chung_lu, grid2d, rmat, table2_workloads, uniform_random
 from repro.graph.sampler import NeighborSampler
 from repro.graph.structs import build_ell, to_device_edges
-from repro.graph.vertex_program import run, run_traced
+from repro.graph.vertex_program import run, run_loop, run_traced, traced_step
 
 
 @pytest.fixture(scope="module")
@@ -52,6 +52,27 @@ class TestAlgorithms:
         a = run(g, bfs_program(), source=0).props
         b = run_traced(g, bfs_program(), source=0).props
         np.testing.assert_allclose(a, b)
+
+    def test_graph_enters_the_step_as_arguments(self):
+        """Both compiled programs take the edge arrays as arguments of
+        `main`; no edge list is baked into the program as a constant (a
+        20k-edge constant alone would print as ~160 kB of hex)."""
+        g = rmat(2000, 20_000, seed=9)
+        program = pagerank_program()
+        gp = prepare_graph("pagerank", g)
+        e = to_device_edges(gp)
+        graph = (e.src, e.dst, e.valid, e.weight)
+        aux = program.make_aux(gp)
+        props, active = program.init(gp.num_nodes, 0)
+        for lowered in (
+            traced_step.lower(program, graph, props, active, aux),
+            run_loop.lower(program, 200, graph, props, active, aux),
+        ):
+            text = lowered.as_text()
+            main = next(ln for ln in text.splitlines() if "func.func public @main" in ln)
+            assert main.count("tensor<20000xi32>") == 2, main  # src, dst
+            assert "tensor<20000xi1>" in main and "tensor<20000xf32>" in main, main
+            assert len(text) < 40_000
 
     def test_padded_edges_are_inert(self, graphs):
         g = graphs[0]

@@ -12,17 +12,12 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def run_subprocess(body: str, devices: int = 8, timeout: int = 560) -> str:
-    # The prelude mirrors the in-process suite's environment: the host-device
-    # flag is APPENDED to any inherited XLA_FLAGS (not clobbered) and must
-    # precede jax's first import; the jax-0.5 API shims (AxisType, set_mesh,
-    # shard_map — conftest installs them in-process) are installed right
-    # after, so the 2×4 / 8-engine mesh bodies below run on jax 0.4 too.
+    # The host-device flag is APPENDED to any inherited XLA_FLAGS (not
+    # clobbered) and must precede jax's first import.
     prog = textwrap.dedent(f"""
         import os
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count={devices}").strip()
-        from repro.compat import install_jax05_compat
-        install_jax05_compat()
         {textwrap.indent(textwrap.dedent(body), '        ').lstrip()}
         print("SUBPROCESS_OK")
     """)

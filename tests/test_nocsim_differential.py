@@ -107,7 +107,7 @@ class TestCreditArmPerWindow:
         depth=st.sampled_from([0.5, 1.0, 2.0, 8.0]),
         topo=st.sampled_from([Mesh2D(4, 4), Torus2D(4, 4), Torus3D(3, 3, 2)]),
     )
-    @settings(max_examples=12)
+    @settings(max_examples=12, deadline=None)
     def test_state_timelines_match(self, seed, depth, topo):
         program = _credit_program(topo, seed, depth=depth)
         tl_np, carry_np = run_credit(program, backend="numpy")
@@ -122,7 +122,7 @@ class TestCreditArmPerWindow:
         _assert_state_close(carry_jx[1], carry_np[1])
 
     @given(seed=st.integers(0, 100_000), depth=st.sampled_from([0.5, 2.0]))
-    @settings(max_examples=8)
+    @settings(max_examples=8, deadline=None)
     def test_scalars_within_contract(self, seed, depth):
         t, pl = _setup(Torus2D(4, 4), seed)
         noc = NocSimParams(flow_control="credit", buffer_depth=depth)
@@ -140,7 +140,7 @@ class TestDegradedCreditComposition:
     its exact identities at the edges of the knob space."""
 
     @given(seed=st.integers(0, 100_000), depth=st.sampled_from([0.5, 1.0, 4.0]))
-    @settings(max_examples=8)
+    @settings(max_examples=8, deadline=None)
     def test_numpy_jax_parity_under_faults(self, seed, depth):
         topo = Mesh2D(4, 4)
         t, pl = _setup(topo, seed)

@@ -25,6 +25,13 @@ missing, truncated, or hash-mismatched shard invalidates only itself: that
 one block is recomputed and rewritten while every other shard still hits.
 `edge_block=None` keeps the historical single-file path byte-for-byte.
 
+Inside the sweep's spans the cache times its own work as span arguments
+(`obs.timer`, none overlapping another): `hash_ns` (the graph digest, the
+partition and activity hashes, the keys), `read_ns` (`np.load` of hits and
+shards), `partition_ns` (`partition_by_name`), `traffic_ns` (the traffic
+computes and the merge of shards), and in `trace`, `host_ns` (preparing the
+algorithm's graph; `run_traced` adds its own).
+
 Crash safety: every cache write (trace, traffic, shard) goes through
 `_atomic_savez` — same-directory temp file, `fsync` of the payload, then
 `os.replace` — so a `kill -9` mid-write can never leave a torn entry behind
@@ -45,6 +52,7 @@ import weakref
 
 import numpy as np
 
+from repro import obs
 from repro.core.partition import Partition, partition_by_name
 from repro.core.traffic import (
     DENSE_MATERIALIZE_MAX,
@@ -151,14 +159,16 @@ def _load_shard(
     missing, unreadable (truncated/corrupt zip), structurally wrong, or its
     stored content hash does not match the payload.  Transient `OSError`s are
     retried before the shard is given up on."""
-    if not os.path.exists(path):
-        return None
-    try:
-        keys, vals, total, stored = _retrying(lambda: _read_shard_payload(path), stats)
-    except Exception:  # BadZipFile, KeyError, OSError, pickle refusal, ...
-        return None
-    if stored != _shard_sha(keys, vals, total):
-        return None
+    with obs.timer("read_ns"):
+        if not os.path.exists(path):
+            return None
+        try:
+            keys, vals, total, stored = _retrying(lambda: _read_shard_payload(path), stats)
+        except Exception:  # BadZipFile, KeyError, OSError, pickle refusal, ...
+            return None
+    with obs.timer("hash_ns"):
+        if stored != _shard_sha(keys, vals, total):
+            return None
     return keys, vals, total
 
 
@@ -181,7 +191,8 @@ class SweepCache:
         key = id(g)
         d = self._graph_digests.get(key)
         if d is None:
-            d = graph_digest(g)
+            with obs.timer("hash_ns"):
+                d = graph_digest(g)
             try:
                 weakref.finalize(g, self._graph_digests.pop, key, None)
             except TypeError:  # not weakref-able: skip the memo entirely
@@ -202,18 +213,20 @@ class SweepCache:
         max_iterations: int = 200,
     ) -> TraceResult:
         """Load or compute the communication trace of `algorithm` on `g`."""
-        key = _key(
-            "trace",
-            {
-                "graph": self._digest_of(g),
-                "alg": algorithm,
-                "source": source,
-                "max_iterations": max_iterations,
-            },
-        )
+        digest = self._digest_of(g)
+        with obs.timer("hash_ns"):
+            key = _key(
+                "trace",
+                {
+                    "graph": digest,
+                    "alg": algorithm,
+                    "source": source,
+                    "max_iterations": max_iterations,
+                },
+            )
         path = self._path(key)
         if path is not None and os.path.exists(path):
-            with np.load(path) as z:
+            with obs.timer("read_ns"), np.load(path) as z:
                 self.stats.trace_hits += 1
                 return TraceResult(
                     props=z["props"],
@@ -228,7 +241,8 @@ class SweepCache:
         from repro.graph.algorithms import ALGORITHMS, prepare_graph
         from repro.graph.vertex_program import run_traced
 
-        prepared = prepare_graph(algorithm, g)
+        with obs.timer("host_ns"):
+            prepared = prepare_graph(algorithm, g)
         tr = run_traced(
             prepared, ALGORITHMS[algorithm](), source=source, max_iterations=max_iterations
         )
@@ -264,41 +278,45 @@ class SweepCache:
         `traffic_from_partition`: "dense", "sparse", or "auto"."""
         if layout not in ("dense", "sparse", "auto"):
             raise ValueError(f"unknown layout {layout!r}; options: dense|sparse|auto")
-        meta = {
-            "graph": self._digest_of(g),
-            "partition": hashlib.sha256(
-                partition.vertex_part.tobytes() + partition.edge_part.tobytes()
-            ).hexdigest(),
-            "parts": partition.num_parts,
-            "activity": hashlib.sha256(trace.edge_activity.tobytes()).hexdigest(),
-            "model": model,
-            "packet_bytes": packet_bytes,
-        }
+        digest = self._digest_of(g)
+        with obs.timer("hash_ns"):
+            meta = {
+                "graph": digest,
+                "partition": hashlib.sha256(
+                    partition.vertex_part.tobytes() + partition.edge_part.tobytes()
+                ).hexdigest(),
+                "parts": partition.num_parts,
+                "activity": hashlib.sha256(trace.edge_activity.tobytes()).hexdigest(),
+                "model": model,
+                "packet_bytes": packet_bytes,
+            }
         if edge_block is not None:
             return self._traffic_sharded(
                 g, partition, trace, meta, model, packet_bytes, layout, int(edge_block)
             )
-        key = _key("traffic", meta)
+        with obs.timer("hash_ns"):
+            key = _key("traffic", meta)
         path = self._path(key)
         if path is not None and os.path.exists(path):
-            with np.load(path) as z:
-                self.stats.traffic_hits += 1
+            with obs.timer("read_ns"), np.load(path) as z:
                 t = TrafficMatrix(
                     num_parts=int(z["num_parts"]),
                     bytes_matrix=z["bytes_matrix"],
                     phase_bytes={k: float(z[f"phase_{k}"]) for k in ("process", "reduce", "apply")},
                 )
-                return self._as_layout(t, layout)
+            self.stats.traffic_hits += 1
+            return self._as_layout(t, layout)
         self.stats.traffic_misses += 1
-        t = traffic_from_partition(
-            partition,
-            g.src,
-            g.dst,
-            edge_activity=trace.edge_activity,
-            vertex_activity=trace.vertex_activity,
-            packet_bytes=packet_bytes,
-            model=model,
-        )
+        with obs.timer("traffic_ns"):
+            t = traffic_from_partition(
+                partition,
+                g.src,
+                g.dst,
+                edge_activity=trace.edge_activity,
+                vertex_activity=trace.vertex_activity,
+                packet_bytes=packet_bytes,
+                model=model,
+            )
         if path is not None:
             _atomic_savez(
                 path,
@@ -335,7 +353,8 @@ class SweepCache:
 
         step = max(edge_block, 1)
         meta = {**meta, "edge_block": step}
-        key = _key("traffic-shards", meta)
+        with obs.timer("hash_ns"):
+            key = _key("traffic-shards", meta)
         e_total = int(np.asarray(g.src).size)
         v_total = int(partition.num_nodes)
         n = 4 * partition.num_parts
@@ -355,7 +374,8 @@ class SweepCache:
                     self.stats.shard_hits += 1
                     return cached
             self.stats.shard_misses += 1
-            keys, vals, total = compute()
+            with obs.timer("traffic_ns"):
+                keys, vals, total = compute()
             if path is not None:
                 _retrying(
                     lambda: _atomic_savez(
@@ -387,7 +407,8 @@ class SweepCache:
                     hi=hi,
                 ),
             )
-            acc.add(keys_b, vals_b)
+            with obs.timer("traffic_ns"):
+                acc.add(keys_b, vals_b)
             w_sum += total_b
         keys_v, vals_v, wv_sum = resolve(
             n_edge_shards,
@@ -399,24 +420,25 @@ class SweepCache:
                 hi=v_total,
             ),
         )
-        acc.add(keys_v, vals_v)
+        with obs.timer("traffic_ns"):
+            acc.add(keys_v, vals_v)
 
-        keep = acc.vals != 0.0
-        keys, vals = acc.keys[keep], acc.vals[keep]
-        sparse = SparseTraffic(
-            num_parts=partition.num_parts,
-            rows=keys // n,
-            cols=keys % n,
-            vals=vals,
-            phase_bytes={
-                "process": 2.0 * w_sum,
-                "reduce": 2.0 * w_sum,
-                "apply": float(wv_sum),
-            },
-        )
-        if layout == "sparse" or (layout == "auto" and n > DENSE_MATERIALIZE_MAX):
-            return sparse
-        return sparse.to_dense()
+            keep = acc.vals != 0.0
+            keys, vals = acc.keys[keep], acc.vals[keep]
+            sparse = SparseTraffic(
+                num_parts=partition.num_parts,
+                rows=keys // n,
+                cols=keys % n,
+                vals=vals,
+                phase_bytes={
+                    "process": 2.0 * w_sum,
+                    "reduce": 2.0 * w_sum,
+                    "apply": float(wv_sum),
+                },
+            )
+            if layout == "sparse" or (layout == "auto" and n > DENSE_MATERIALIZE_MAX):
+                return sparse
+            return sparse.to_dense()
 
     # -------------------------------------------------------------- partition
     def partition(
@@ -424,4 +446,5 @@ class SweepCache:
     ) -> Partition:
         """Partitions are cheap to recompute; kept here only so sweep code has
         one entry point per derived artifact (no disk round-trip)."""
-        return partition_by_name(partitioner, g.src, g.dst, g.num_nodes, num_parts, **kw)
+        with obs.timer("partition_ns"):
+            return partition_by_name(partitioner, g.src, g.dst, g.num_nodes, num_parts, **kw)

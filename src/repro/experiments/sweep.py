@@ -326,28 +326,29 @@ def run_sweep(
         )
 
     memory["batched_eval_mb"] = peak_rss_mb()
-    shared_us = (t_batched + t_placement) * 1e6 / max(1, len(configs))
-    records = []
-    for c, traffic, placement, res, cfg_us in zip(
-        configs, traffics, placements, results, per_config_us
-    ):
-        g = gmap[(c.workload, c.scale)]
-        graph_bytes = (g.num_edges * 2 + g.num_nodes) * 8  # ET + props @ 8B words
-        records.append(
-            SweepRecord(
-                config=c,
-                num_nodes=g.num_nodes,
-                num_edges=g.num_edges,
-                num_iterations=int(iters[len(records)]),
-                placement_method=placement.method,
-                edge_balance=partitions[
-                    (c.workload, c.scale, c.partitioner, c.num_parts)
-                ].edge_balance(),
-                phase_norm=traffic.normalized_by(graph_bytes),
-                result=res,
-                elapsed_us=cfg_us + shared_us,
+    with span("sweep.records", cat="sweep", grid=grid.name):
+        shared_us = (t_batched + t_placement) * 1e6 / max(1, len(configs))
+        records = []
+        for c, traffic, placement, res, cfg_us in zip(
+            configs, traffics, placements, results, per_config_us
+        ):
+            g = gmap[(c.workload, c.scale)]
+            graph_bytes = (g.num_edges * 2 + g.num_nodes) * 8  # ET + props @ 8B words
+            records.append(
+                SweepRecord(
+                    config=c,
+                    num_nodes=g.num_nodes,
+                    num_edges=g.num_edges,
+                    num_iterations=int(iters[len(records)]),
+                    placement_method=placement.method,
+                    edge_balance=partitions[
+                        (c.workload, c.scale, c.partitioner, c.num_parts)
+                    ].edge_balance(),
+                    phase_norm=traffic.normalized_by(graph_bytes),
+                    result=res,
+                    elapsed_us=cfg_us + shared_us,
+                )
             )
-        )
 
     # ---- windowed contention pass (repro.nocsim, `--grid contention`) ------
     contention = None
@@ -414,8 +415,9 @@ def run_sweep(
     # a private registry so §Perf renders exactly this sweep's numbers even
     # when several sweeps share a process (counters would otherwise
     # accumulate across runs).
-    register_sweep_metrics(result)
-    metrics_snapshot_for(result)
+    with span("sweep.metrics", cat="sweep", grid=grid.name):
+        register_sweep_metrics(result)
+        metrics_snapshot_for(result)
     return result
 
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.graph.structs import HostGraph, to_device_edges
 
 __all__ = [
@@ -166,9 +167,16 @@ def run_traced(
     pad_to: int | None = None,
 ) -> TraceResult:
     """Python-loop execution that records the communication trace
-    (per-edge/vertex activity) for the NoC simulator."""
-    graph, aux = _device_graph(g, program, pad_to)
-    props, active = program.init(g.num_nodes, source)
+    (per-edge/vertex activity) for the NoC simulator.
+
+    Inside a span it records, as that span's arguments (`obs.timer` and
+    `obs.count`): `wait_ns`, the frontier test and each step until its
+    outputs are ready; `host_ns`, the edge upload and per iteration the
+    copies to the host and the float64 accumulations; `d2h_bytes`, the
+    bytes of every array copied to the host."""
+    with obs.timer("host_ns"):
+        graph, aux = _device_graph(g, program, pad_to)
+        props, active = program.init(g.num_nodes, source)
 
     e_real = g.num_edges
     edge_activity = np.zeros(e_real, dtype=np.float64)
@@ -176,20 +184,31 @@ def run_traced(
     frontier_sizes: list[int] = []
     it = 0
     while it < max_iterations:
-        if program.frontier == "delta" and not bool(jnp.any(active)):
-            break
-        new_props, new_active, edge_active = traced_step(program, graph, props, active, aux)
-        edge_activity += np.asarray(edge_active)[:e_real]
-        changed = np.asarray(new_props != props)[:-1]
-        vertex_activity += changed
-        frontier_sizes.append(int(np.asarray(edge_active).sum()))
-        delta = float(np.nan_to_num(np.abs(np.asarray(new_props - props)), posinf=0.0).sum())
+        with obs.timer("wait_ns"):
+            if program.frontier == "delta" and not bool(jnp.any(active)):
+                break
+            new_props, new_active, edge_active = jax.block_until_ready(
+                traced_step(program, graph, props, active, aux)
+            )
+        with obs.timer("host_ns"):
+            # one host copy of the step's edge mask serves both uses
+            edge_host = np.asarray(edge_active)
+            edge_activity += edge_host[:e_real]
+            changed = np.asarray(new_props != props)
+            vertex_activity += changed[:-1]
+            frontier_sizes.append(int(edge_host.sum()))
+            diff = np.asarray(new_props - props)
+            delta = float(np.nan_to_num(np.abs(diff), posinf=0.0).sum())
+            obs.count("d2h_bytes", edge_host.nbytes + changed.nbytes + diff.nbytes)
         props, active = new_props, new_active
         it += 1
         if program.frontier == "all" and delta <= program.tol:
             break
+    with obs.timer("host_ns"):
+        final = np.asarray(props[:-1])
+        obs.count("d2h_bytes", final.nbytes)
     return TraceResult(
-        props=np.asarray(props[:-1]),
+        props=final,
         num_iterations=it,
         edge_activity=edge_activity,
         vertex_activity=vertex_activity,

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.analysis.registry import parity_pair
 from repro.obs import span
 from repro.core.placement import Placement
@@ -137,6 +138,7 @@ def _step_jax(
         else jnp.asarray(backlog0, dtype=jnp.float32)
     )
     serviced, backlog = _jax_step_fn()(jnp.asarray(inj, dtype=jnp.float32), init)
+    obs.count("dispatches")
     return np.asarray(serviced, np.float64), np.asarray(backlog, np.float64)
 
 

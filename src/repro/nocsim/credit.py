@@ -77,6 +77,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.nocsim.batch import run_windows
 from repro.nocsim.model import ConfigSchedule, NocSimParams, normalize_buffer_depth
 from repro.precision import DOT_PRECISION
@@ -323,6 +324,7 @@ def _credit_step_jax(program: CreditProgram):
             pair_c,
             depth,
         )
+        obs.count("dispatches")
         return (
             tuple(np.asarray(t, np.float64) for t in tls),
             (np.asarray(src, np.float64), np.asarray(buf, np.float64)),

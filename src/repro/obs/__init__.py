@@ -13,6 +13,7 @@ from .recorder import FlightRecorder
 from .trace import (
     Span,
     Tracer,
+    count,
     deterministic_clock_active,
     disable_tracing,
     enable_tracing,
@@ -21,6 +22,7 @@ from .trace import (
     now_ns,
     now_s,
     span,
+    timer,
     tracing_enabled,
 )
 
@@ -30,6 +32,8 @@ __all__ = [
     "FlightRecorder",
     "metrics",
     "span",
+    "count",
+    "timer",
     "now_ns",
     "now_s",
     "get_tracer",

@@ -30,6 +30,21 @@ Buffering and safety:
     `duration_s` feeds the metrics/payload paths — but nothing is
     buffered, so the steady-state cost is two clock reads.
 
+Counters and timers inside a span:
+
+  * Every thread keeps a stack of its open spans (`Span.__enter__` pushes,
+    `__exit__` pops, tracing on or off).  `count(key, amount)` adds to
+    `args[key]` of this thread's innermost open span; `with timer(key):`
+    adds the obs-clock nanoseconds of its block there.  Both write only
+    while tracing is enabled, so the work they describe shows up in the
+    exported span's args without a child span splitting its time.
+  * `timer` reads the clock twice whether or not tracing is enabled — the
+    same rule as `Span.__exit__`, so the deterministic clock's read count
+    never depends on tracing.  `count` with tracing off is one attribute
+    check; its amount must be free to compute (`.nbytes`, `len`).
+  * A timer writes to the span that was innermost when it started, and only
+    if that span is still open on this thread when it stops.
+
 Export is the Chrome trace event format (`{"traceEvents": [...]}`,
 timestamps/durations in microseconds), the JSON flavour `ui.perfetto.dev`
 and `chrome://tracing` both load directly.  Wall-clock stays strictly out
@@ -48,6 +63,8 @@ __all__ = [
     "Span",
     "Tracer",
     "span",
+    "count",
+    "timer",
     "now_ns",
     "now_s",
     "get_tracer",
@@ -80,6 +97,18 @@ def now_ns() -> int:
     return time.monotonic_ns()
 
 
+# Per-thread stack of open spans: what `count` and `timer` write into.
+_LOCAL = threading.local()
+
+
+def _open_spans() -> list:
+    try:
+        return _LOCAL.spans
+    except AttributeError:
+        _LOCAL.spans = []
+        return _LOCAL.spans
+
+
 def now_s() -> float:
     """`now_ns` in seconds — the drop-in for `time.perf_counter()` call
     sites that feed durations into payload dicts."""
@@ -104,11 +133,15 @@ class Span:
         self.dur_ns = 0
 
     def __enter__(self) -> "Span":
+        _open_spans().append(self)
         self.start_ns = now_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.dur_ns = now_ns() - self.start_ns
+        stack = _open_spans()
+        if self in stack:  # spans left open above this one close with it
+            del stack[stack.index(self):]
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         tracer = _TRACER
@@ -130,6 +163,49 @@ class Span:
 def span(name: str, cat: str = "pipeline", **args) -> Span:
     """`with span("sweep.trace", grid="mini") as sp: ...` — the one idiom."""
     return Span(name, cat, **args)
+
+
+def count(key: str, amount=1) -> None:
+    """Add `amount` to `args[key]` of this thread's innermost open span
+    (nothing while tracing is off, or outside every span)."""
+    if not _TRACER.enabled:
+        return
+    stack = _open_spans()
+    if stack:
+        args = stack[-1].args
+        args[key] = args.get(key, 0) + amount
+
+
+class _Timer:
+    """`with timer(key):` — see `timer`."""
+
+    __slots__ = ("key", "span", "start_ns")
+
+    def __init__(self, key: str):
+        self.key = key
+        self.span = None
+        self.start_ns = 0
+
+    def __enter__(self) -> "_Timer":
+        if _TRACER.enabled:
+            stack = _open_spans()
+            self.span = stack[-1] if stack else None
+        self.start_ns = now_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        elapsed = now_ns() - self.start_ns
+        sp = self.span
+        if sp is not None and _TRACER.enabled and sp in _open_spans():
+            sp.args[self.key] = sp.args.get(self.key, 0) + elapsed
+        return False
+
+
+def timer(key: str) -> _Timer:
+    """`with timer("hash_ns"): ...` — add the block's obs-clock nanoseconds
+    to `args[key]` of the span that is innermost when it starts.  Creates no
+    span; reads the clock twice whether or not tracing is enabled."""
+    return _Timer(key)
 
 
 def _json_safe(value):

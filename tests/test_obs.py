@@ -189,6 +189,109 @@ class TestSpanTracer:
 
 
 # ---------------------------------------------------------------------------
+# Counters and timers (span arguments)
+# ---------------------------------------------------------------------------
+
+
+class TestCountersAndTimers:
+    def test_count_and_timer_land_on_the_innermost_open_span(self, clean_tracer):
+        obs.enable_tracing()
+        with span("outer", cat="test") as outer:
+            obs.count("items", 2)
+            with span("inner", cat="test") as inner:
+                obs.count("items", 3)
+                obs.count("items")
+                with obs.timer("work_ns"):
+                    pass
+            obs.count("items", 5)
+            with obs.timer("work_ns"):
+                pass
+            with obs.timer("work_ns"):
+                pass
+        assert inner.args["items"] == 4 and outer.args["items"] == 7
+        assert inner.args["work_ns"] >= 0 and outer.args["work_ns"] >= 0
+        assert inner.args["work_ns"] + outer.args["work_ns"] <= outer.dur_ns
+        obs.count("items", 100)  # no open span: written nowhere
+        with obs.timer("work_ns"):
+            pass
+        assert outer.args["items"] == 7
+
+    def test_each_thread_writes_to_its_own_span(self, clean_tracer):
+        obs.enable_tracing()
+        n_workers, n_counts = 4, 50
+        barrier = threading.Barrier(n_workers)
+        spans = {}
+
+        def worker(i):
+            with span(f"w{i}", cat="test") as sp:
+                spans[i] = sp
+                barrier.wait()  # every thread's span open at once
+                for _ in range(n_counts):
+                    obs.count("n", i + 1)
+                    with obs.timer("t_ns"):
+                        pass
+
+        threads = [threading.Thread(target=worker, args=(i,)) for i in range(n_workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for i, sp in spans.items():
+            assert sp.args["n"] == n_counts * (i + 1)
+            assert 0 <= sp.args["t_ns"] <= sp.dur_ns
+
+    def test_nothing_is_written_with_tracing_off(self, clean_tracer):
+        with span("quiet", cat="test") as sp:
+            obs.count("items", 3)
+            with obs.timer("work_ns"):
+                pass
+        assert sp.args == {}
+        assert clean_tracer.spans() == []
+
+    def test_timer_that_outlives_its_span_writes_nowhere(self, clean_tracer):
+        obs.enable_tracing()
+        with span("outer", cat="test") as outer:
+            t = obs.timer("work_ns")
+            with span("inner", cat="test") as inner:
+                t.__enter__()
+            t.__exit__(None, None, None)  # its span has closed
+            obs.count("after", 1)
+        assert "work_ns" not in inner.args and "work_ns" not in outer.args
+        assert outer.args["after"] == 1
+
+    def test_span_left_open_above_an_exiting_span_is_dropped(self, clean_tracer):
+        obs.enable_tracing()
+        with span("outer", cat="test") as outer:
+            span("leaked", cat="test").__enter__()
+        with span("next", cat="test") as nxt:
+            obs.count("items")
+        assert nxt.args == {"items": 1} and "items" not in outer.args
+
+    def test_clock_reads_do_not_depend_on_tracing(self):
+        """Under REPRO_OBS_DETERMINISTIC=1 a timer reads the clock twice
+        and a count not at all, tracing on or off, so payload timings stay
+        byte-identical when tracing is turned on."""
+        body = (
+            "from repro import obs\n"
+            "def reads():\n"
+            "    a = obs.now_ns()\n"
+            "    with obs.span('s'):\n"
+            "        obs.count('n', 1)\n"
+            "        with obs.timer('t_ns'):\n"
+            "            obs.count('n', 2)\n"
+            "    return (obs.now_ns() - a) // 1000\n"
+            "off = reads()\n"
+            "obs.enable_tracing()\n"
+            "on = reads()\n"
+            "(sp,) = obs.get_tracer().spans()\n"
+            "assert sp.args == {'n': 3, 't_ns': 1000}, sp.args\n"
+            "assert off == on == 5, (off, on)\n"
+        )
+        env = dict(os.environ, PYTHONPATH=SRC, REPRO_OBS_DETERMINISTIC="1")
+        subprocess.run([sys.executable, "-c", body], env=env, check=True, timeout=120)
+
+
+# ---------------------------------------------------------------------------
 # Chrome-trace schema
 # ---------------------------------------------------------------------------
 

@@ -24,6 +24,14 @@ in tests/test_sparse_traffic.py): traffic bytes are integer-valued float64
 (iteration counts × packet bytes), and sums of integers below 2^53 are exact
 in float64 under ANY association — so the sparse/blocked accumulation is
 bit-identical to the dense `np.bincount` path, not merely close.
+
+Per block, every flow's key is a function of a part pair (part(e), part(src))
+— or part(e) alone, or (part(e), part(dst)) under model="cross" — so a block
+reduces to one P²-bin histogram of its edges' part pairs (`np.bincount`, one
+pass, no sort), from which the four flows' keys are scattered.  Past
+P² > block length the histogram would outgrow the block, and one sort of the
+joint pair key (`np.unique`) takes its place; either way the block's output
+is the same canonical (sorted keys, sums, total) triplet.
 """
 from __future__ import annotations
 
@@ -31,6 +39,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.core.partition import Partition
 
 __all__ = [
@@ -197,11 +206,12 @@ class SparseTraffic:
 class _COOAccumulator:
     """Streaming (key → Σ weight) accumulator over int64 flat keys.
 
-    Each `add` bincounts one block's contributions over its *present* keys
-    only (never n² storage) and merges into the running triplet set via one
-    `np.unique` — O(nnz log nnz) per merge, nnz ≤ (4P)².  Exactness: the
-    weights are integer-valued (counts × packet bytes), so the re-association
-    across blocks is bit-exact vs the dense single-pass bincount."""
+    Merges the blocks' (keys, vals) triplets, each already reduced to its
+    present keys by `edge_block_coo`/`vertex_block_coo`, into the running
+    set via one `np.unique` — O(nnz log nnz) per merge, nnz ≤ (4P)², never
+    n² storage.  Exactness: the weights are integer-valued (counts × packet
+    bytes), so the re-association across blocks is bit-exact vs the dense
+    single-pass bincount."""
 
     def __init__(self) -> None:
         self.keys = np.empty(0, dtype=np.int64)
@@ -224,6 +234,30 @@ def _accumulate(matrix: np.ndarray, from_ids: np.ndarray, to_ids: np.ndarray, w:
     matrix.reshape(-1)[:] += np.bincount(flat, weights=w, minlength=n * n)
 
 
+def _key_sums(
+    k: np.ndarray, w: np.ndarray, bins: int, histogram: bool
+) -> tuple[np.ndarray, np.ndarray]:
+    """The distinct values of `k` (each in [0, bins)), sorted, and the sum of
+    `w` over each — a key whose weights are all zero is kept.  `histogram`
+    bincounts over all `bins`; otherwise one sort of `k` (`np.unique`)."""
+    if histogram:
+        present = np.flatnonzero(np.bincount(k, minlength=bins))
+        return present, np.bincount(k, weights=w, minlength=bins)[present]
+    keys, inv = np.unique(k, return_inverse=True)
+    # float64 also for an empty block, where bincount would return int64.
+    return keys, np.bincount(inv, weights=w, minlength=keys.size).astype(np.float64, copy=False)
+
+
+def _use_histogram(bins: int, size: int) -> bool:
+    """Histogram (True) or sort for a block of `size` keys over `bins`
+    values: the histogram while it is no larger than the block, so a block's
+    transients stay O(block) at any P.  Counted on the innermost open span
+    as `hist_blocks` / `sort_blocks`."""
+    histogram = bins <= size
+    obs.count("hist_blocks" if histogram else "sort_blocks")
+    return histogram
+
+
 def edge_block_coo(
     partition: Partition,
     src: np.ndarray,
@@ -241,28 +275,45 @@ def edge_block_coo(
     reduce_bytes = 2·Σ w_sum over blocks).  One edge block is independently
     recomputable — the unit of both the streaming accumulation in
     `traffic_from_partition` and the disk shards in
-    `repro.experiments.cache`."""
+    `repro.experiments.cache`.
+
+    Every flow's key is a function of a part pair: ET→vprop and vprop→eprop
+    of (part(e), part(src)); eprop→vtemp and ET→vtemp of part(e) alone under
+    "paper" (the pair sums' row totals) and of (part(e), part(dst)) under
+    "cross".  So the block reduces to Σ w per present part pair — a P²-bin
+    histogram (`_key_sums`) — and the flows' keys are scattered from those
+    pairs.  The result is the canonical form: sorted unique keys, a present
+    key with a zero sum kept."""
     P = partition.num_parts
     n = 4 * P
-    src = np.asarray(src, dtype=np.int64)[lo:hi]
-    dst = np.asarray(dst, dtype=np.int64)[lo:hi]
+    src = np.asarray(src)[lo:hi]
     if edge_activity is None:
         w = np.full(src.size, float(packet_bytes), dtype=np.float64)
     else:
         w = np.asarray(edge_activity[lo:hi], dtype=np.float64) * packet_bytes
     ep = partition.edge_part[lo:hi].astype(np.int64)
-    sp = partition.vertex_part[src].astype(np.int64)
-    dp = partition.vertex_part[dst].astype(np.int64)
-    et = ET * P + ep
-    eprop = EPROP * P + ep
-    vprop = VPROP * P + sp
-    vtemp = VTEMP * P + (ep if model == "paper" else dp)
-    acc = _COOAccumulator()
-    acc.add(et * n + vprop, w)
-    acc.add(vprop * n + eprop, w)
-    acc.add(eprop * n + vtemp, w)
-    acc.add(et * n + vtemp, w)
-    return acc.keys, acc.vals, float(w.sum())
+    histogram = _use_histogram(P * P, src.size)
+    pairs, sums = _key_sums(ep * P + partition.vertex_part[src], w, P * P, histogram)
+    e, s = pairs // P, pairs % P
+    if model == "paper":
+        # Pairs are sorted by part(e) first, so each part's run is contiguous.
+        rows, first = np.unique(e, return_index=True)
+        cols = rows
+        vt = np.add.reduceat(sums, first)
+    else:
+        dst = np.asarray(dst)[lo:hi]
+        pairs_d, vt = _key_sums(ep * P + partition.vertex_part[dst], w, P * P, histogram)
+        rows, cols = pairs_d // P, pairs_d % P
+    keys = np.concatenate([
+        (ET * P + e) * n + VPROP * P + s,
+        (VPROP * P + s) * n + EPROP * P + e,
+        (EPROP * P + rows) * n + VTEMP * P + cols,
+        (ET * P + rows) * n + VTEMP * P + cols,
+    ])
+    vals = np.concatenate([sums, sums, vt, vt])
+    # The four flows' (row, column) structure pairs differ, so no key repeats.
+    order = np.argsort(keys)
+    return keys[order], vals[order], float(w.sum())
 
 
 def vertex_block_coo(
@@ -274,7 +325,8 @@ def vertex_block_coo(
     hi: int,
 ) -> tuple[np.ndarray, np.ndarray, float]:
     """COO contribution of vertices [lo, hi): the Apply phase's local
-    vtemp→vprop flow.  Returns (keys, vals, wv_sum)."""
+    vtemp→vprop flow, keyed by part(v) alone, so a P-bin histogram.
+    Returns (keys, vals, wv_sum)."""
     P = partition.num_parts
     n = 4 * P
     if vertex_activity is None:
@@ -282,9 +334,8 @@ def vertex_block_coo(
     else:
         wv = np.asarray(vertex_activity[lo:hi], dtype=np.float64) * packet_bytes
     vp = partition.vertex_part[lo:hi].astype(np.int64)
-    acc = _COOAccumulator()
-    acc.add((VTEMP * P + vp) * n + (VPROP * P + vp), wv)
-    return acc.keys, acc.vals, float(wv.sum())
+    parts, sums = _key_sums(vp, wv, P, _use_histogram(P, vp.size))
+    return (VTEMP * P + parts) * n + VPROP * P + parts, sums, float(wv.sum())
 
 
 def _resolve_layout(layout: str, num_logical: int) -> str:

@@ -10,6 +10,9 @@ explicitly in play (tolerances stated inline).
 Covered, per random graph × all four topologies × both traffic models:
   * traffic matrices: dense single-pass vs sparse/blocked/auto layouts,
     every edge-block size, plus the `SweepCache` shard path;
+  * one block's COO (`edge_block_coo`/`vertex_block_coo`, histogram or sort)
+    vs the four-sort reference the shard payloads were first written in,
+    byte for byte;
   * H evaluation: `sparse_weighted_hops` (+ the batched numpy/jax versions)
     vs the dense `Placement.weighted_hops`;
   * per-step swap/move deltas: `swap_delta_pairs` vs the dense
@@ -19,13 +22,18 @@ Covered, per random graph × all four topologies × both traffic models:
     `contended_batch(window_chunk=...)` vs their unchunked runs, on both
     backends, for arbitrary chunk sizes.
 """
+import contextlib
+import dataclasses
+
 from _hypothesis_compat import given, settings, st
+from _traffic_oracle import edge_block_oracle, vertex_block_oracle
 
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core.noc import FlattenedButterfly, Mesh2D, Torus2D, Torus3D
-from repro.core.partition import powerlaw_partition
+from repro.core.partition import powerlaw_partition, random_partition
 from repro.core.placement import (
     default_max_steps,
     random_placement,
@@ -36,7 +44,13 @@ from repro.core.placement import (
     two_opt_best_move,
     two_opt_topk,
 )
-from repro.core.traffic import SparseTraffic, TrafficMatrix, traffic_from_partition
+from repro.core.traffic import (
+    SparseTraffic,
+    TrafficMatrix,
+    edge_block_coo,
+    traffic_from_partition,
+    vertex_block_coo,
+)
 from repro.experiments.batched import simulate_batch
 from repro.experiments.placement_batch import (
     batch_descend,
@@ -117,6 +131,102 @@ class TestTrafficParity:
         m = np.zeros((n, n))
         m[rows, cols] = vals
         assert np.array_equal(m, sp.to_dense().symmetrized())
+
+
+def _spilled(g, num_parts: int, seed: int):
+    """A random partition with a fifth of the edges moved off their source's
+    part, as capacity spill does (part(e) ≠ part(src))."""
+    part = random_partition(g.src, g.dst, g.num_nodes, num_parts, seed=seed)
+    rng = np.random.default_rng(seed)
+    edge_part = part.edge_part.copy()
+    moved = rng.random(edge_part.size) < 0.2
+    edge_part[moved] = (edge_part[moved] + rng.integers(1, num_parts, moved.sum())) % num_parts
+    return dataclasses.replace(part, edge_part=edge_part)
+
+
+class TestBlockHistogramVsSort:
+    """`edge_block_coo`/`vertex_block_coo` reduce a block through a part-pair
+    histogram (or one sort where P² passes the block); their output must be
+    byte-identical to the four-sort reference the shard payloads were first
+    written in, so on-disk shards and their hashes stay valid.  P = 4 makes
+    blocks of 1 and 3 sort and larger ones histogram; P = 40 (1600 pair
+    bins > 1200 edges) sorts every edge block."""
+
+    N, E = 150, 1200
+
+    @staticmethod
+    @contextlib.contextmanager
+    def _counting():
+        obs.enable_tracing()
+        try:
+            with obs.span("blocks") as sp:
+                yield sp
+        finally:
+            obs.disable_tracing()
+            obs.get_tracer().reset()
+
+    @staticmethod
+    def _assert_paths(sp, total, step, bins):
+        """Each block took the histogram iff its bins fit in its length."""
+        sizes = [min(step, total - lo) for lo in range(0, total, step)]
+        assert sp.args.get("hist_blocks", 0) == sum(bins <= k for k in sizes)
+        assert sp.args.get("sort_blocks", 0) == sum(bins > k for k in sizes)
+
+    @staticmethod
+    def _assert_same(got, want):
+        assert got[0].dtype == want[0].dtype and np.array_equal(got[0], want[0])
+        assert got[1].dtype == want[1].dtype and got[1].tobytes() == want[1].tobytes()
+        assert got[2] == want[2]
+
+    @pytest.mark.parametrize("num_parts", [4, 40])
+    @pytest.mark.parametrize("block", [1, 3, 17, None])
+    @pytest.mark.parametrize("with_activity", [False, True])
+    @pytest.mark.parametrize("model", ["paper", "cross"])
+    def test_edge_blocks_match_sort_oracle(self, model, with_activity, block, num_parts):
+        g = rmat(self.N, self.E, seed=11)
+        part = _spilled(g, num_parts, seed=11)
+        assert (part.edge_part != part.vertex_part[g.src]).any()
+        ea = None
+        if with_activity:
+            ea = np.random.default_rng(11).integers(0, 4, self.E).astype(np.float64)
+            ea[part.edge_part == 0] = 0.0  # part 0's keys are present with zero sums
+        step = block or self.E
+        zero_sums = 0
+        with self._counting() as sp:
+            for lo in range(0, self.E, step):
+                kw = dict(edge_activity=ea, packet_bytes=8, model=model, lo=lo, hi=min(lo + step, self.E))
+                got = edge_block_coo(part, g.src, g.dst, **kw)
+                self._assert_same(got, edge_block_oracle(part, g.src, g.dst, **kw))
+                zero_sums += int((got[1] == 0.0).sum())
+        assert (zero_sums > 0) == with_activity
+        self._assert_paths(sp, self.E, step, num_parts**2)
+
+    @pytest.mark.parametrize("model", ["paper", "cross"])
+    def test_empty_blocks_match_sort_oracle(self, model):
+        g = rmat(self.N, self.E, seed=17)
+        part = _spilled(g, 4, seed=17)
+        kw = dict(edge_activity=None, packet_bytes=8, model=model, lo=5, hi=5)
+        self._assert_same(edge_block_coo(part, g.src, g.dst, **kw),
+                          edge_block_oracle(part, g.src, g.dst, **kw))
+        kw = dict(vertex_activity=None, packet_bytes=8, lo=5, hi=5)
+        self._assert_same(vertex_block_coo(part, **kw), vertex_block_oracle(part, **kw))
+
+    @pytest.mark.parametrize("num_parts", [4, 40])
+    @pytest.mark.parametrize("block", [1, 3, 17, None])
+    @pytest.mark.parametrize("with_activity", [False, True])
+    def test_vertex_blocks_match_sort_oracle(self, with_activity, block, num_parts):
+        g = rmat(self.N, self.E, seed=13)
+        part = _spilled(g, num_parts, seed=13)
+        va = None
+        if with_activity:
+            va = np.random.default_rng(13).integers(0, 4, self.N).astype(np.float64)
+            va[part.vertex_part == 0] = 0.0
+        step = block or self.N
+        with self._counting() as sp:
+            for lo in range(0, self.N, step):
+                kw = dict(vertex_activity=va, packet_bytes=8, lo=lo, hi=min(lo + step, self.N))
+                self._assert_same(vertex_block_coo(part, **kw), vertex_block_oracle(part, **kw))
+        self._assert_paths(sp, self.N, step, num_parts)
 
 
 class TestPlacementKernelParity:

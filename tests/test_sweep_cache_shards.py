@@ -12,10 +12,11 @@ import os
 
 import numpy as np
 import pytest
+from _traffic_oracle import edge_block_oracle, vertex_block_oracle
 
 from repro.core.partition import powerlaw_partition
 from repro.core.traffic import SparseTraffic, TrafficMatrix, traffic_from_partition
-from repro.experiments.cache import SweepCache, _load_shard
+from repro.experiments.cache import SweepCache, _atomic_savez, _load_shard, _shard_sha
 from repro.graph.generators import rmat
 from repro.graph.vertex_program import TraceResult
 
@@ -128,3 +129,28 @@ def test_uncached_sharded_compute(setup):
     t = cache.traffic(g, part, trace, layout="sparse", edge_block=100)
     _assert_matches(t, dense)
     assert cache.stats.shard_misses == 25  # ceil(2400/100) + 1, nothing stored
+
+
+def test_shards_of_the_sort_form_still_hit(setup):
+    """Shards whose payload the four-sort block form wrote (as caches filled
+    before the part-pair histogram hold them) verify and hit: the histogram
+    blocks hash to the same sha, and the merged matrix is unchanged."""
+    g, part, trace, dense, cache, root = setup
+    cache.traffic(g, part, trace, layout="sparse", edge_block=500)
+    paths = _shards(root)  # edge shards 0..4 in block order, then the vertex shard
+    E = g.src.size
+    payloads = [
+        edge_block_oracle(part, g.src, g.dst, edge_activity=trace.edge_activity,
+                          packet_bytes=8, model="paper", lo=lo, hi=min(lo + 500, E))
+        for lo in range(0, E, 500)
+    ] + [vertex_block_oracle(part, vertex_activity=trace.vertex_activity,
+                             packet_bytes=8, lo=0, hi=g.num_nodes)]
+    assert len(paths) == len(payloads) == 6
+    for path, (keys, vals, total) in zip(paths, payloads):
+        sha = _shard_sha(keys, vals, total)
+        with np.load(path) as z:
+            assert str(z["sha"]) == sha
+        _atomic_savez(path, keys=keys, vals=vals, total=np.float64(total), sha=np.str_(sha))
+    fresh = SweepCache(root)
+    _assert_matches(fresh.traffic(g, part, trace, layout="sparse", edge_block=500), dense)
+    assert fresh.stats.shard_hits == 6 and fresh.stats.shard_misses == 0

@@ -10,6 +10,8 @@ import fnmatch
 import pytest
 
 from repro import obs
+from repro.core.partition import random_partition
+from repro.core.traffic import edge_block_coo
 from repro.experiments.cache import SweepCache
 from repro.experiments.grid import GridSpec
 from repro.experiments.sweep import run_sweep
@@ -109,3 +111,26 @@ def test_no_span_nests_in_a_stage_span(sweeps):
             inside = [s.name for s in spans if s is not outer and s.tid == outer.tid
                       and outer.start_ns <= s.start_ns and s.start_ns + s.dur_ns <= end]
             assert inside == [], (kind, outer.name, inside)
+
+
+def test_blocked_traffic_counts_histogram_blocks(sweeps):
+    """Every blocked traffic build reduces its ceil(E / 700) edge blocks and
+    its one vertex block through the part-pair histogram (P² = 16 bins, no
+    block shorter); the whole-matrix path and cache hits count nothing."""
+    pt = _one(sweeps["blocked"][1], "sweep.partition_traffic").args
+    assert pt["hist_blocks"] == (-(-E // 700) + 1) * pt["configs"]
+    assert pt["configs"] == len(sweeps["blocked"][0].records)
+    assert "sort_blocks" not in pt
+    for kind in ("cold", "warm"):
+        args = _one(sweeps[kind][1], "sweep.partition_traffic").args
+        assert not {"hist_blocks", "sort_blocks"} & set(args)
+
+
+def test_block_counters_record_nothing_while_tracing_is_off():
+    g = rmat(N, E, seed=3)
+    part = random_partition(g.src, g.dst, N, 4)
+    assert not obs.tracing_enabled()
+    with obs.span("blocks") as sp:
+        edge_block_coo(part, g.src, g.dst, edge_activity=None, packet_bytes=8,
+                       model="paper", lo=0, hi=E)
+    assert sp.args == {} and obs.get_tracer().spans() == []

@@ -274,10 +274,12 @@ def run_four_chips(scale: float, phase: Phases, devices) -> None:
     print(f"[mapping] engines {perm.tolist()}; byte-weighted ICI hops {h_id:.4f} -> {h_opt:.4f}")
     mesh = make_engines_mesh(site_permutation=perm, devices=devices)
     with phase("distributed bfs"):
-        bfs_out, bfs_it = DistributedEngine(bfs_program(), mesh).run(g, part, source=0)
+        bfs = DistributedEngine(bfs_program(), mesh)
+        bfs_out, bfs_it = bfs.run(bfs.load(g, part), source=0)
     with phase("distributed pagerank"):
         gp = prepare_graph("pagerank", g)
-        pr_out, pr_it = DistributedEngine(pagerank_program(), mesh).run(gp, part)
+        pagerank = DistributedEngine(pagerank_program(), mesh)
+        pr_out, pr_it = pagerank.run(pagerank.load(gp, part))
     with phase("references"):
         bfs_ref = reference_bfs(g, 0)
         pr_ref = reference_pagerank(gp)

@@ -32,13 +32,13 @@ print(f"ICI hop count (byte-weighted): identity {h_id:.2f} → optimized {h_opt:
 
 mesh = make_engines_mesh(site_permutation=perm)
 engine = DistributedEngine(pagerank_program(), mesh)
-out, iters = engine.run(g, part, max_iterations=100)
+out, iters = engine.run(engine.load(g, part), max_iterations=100)
 err = float(np.nanmax(np.abs(out - reference_pagerank(g))))
 print(f"pagerank: {iters} iterations, max |err| vs reference = {err:.2e}")
 
 # baseline: random partition (same engine) — compare exchanged bytes
 base_part = random_partition(g.src, g.dst, g.num_nodes, P)
-base_out, _ = engine.run(g, base_part, max_iterations=100)
+base_out, _ = engine.run(engine.load(g, base_part), max_iterations=100)
 err_b = float(np.nanmax(np.abs(base_out - reference_pagerank(g))))
 print(f"random partition also converges (err {err_b:.2e}) — correctness is "
       f"mapping-independent; the win is communication:")

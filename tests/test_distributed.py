@@ -22,6 +22,7 @@ from repro.graph.algorithms import (
 )
 from repro.graph.distributed import DistributedEngine, ShardedVertexGraph, make_engines_mesh
 from repro.graph.generators import rmat
+from repro.graph.structs import HostGraph
 from repro.launch.roofline import HW, Roofline, collective_bytes
 
 
@@ -62,21 +63,21 @@ class TestDistributedEngine:
         g = small_powerlaw
         part, mesh = self._engine_parts(g, partitioner)
         eng = DistributedEngine(bfs_program(), mesh)
-        out, it = eng.run(g, part, source=0)
+        out, it = eng.run(eng.load(g, part), source=0)
         np.testing.assert_allclose(out, reference_bfs(g, 0))
 
     def test_sssp_matches_reference(self, small_powerlaw, partitioner):
         g = prepare_graph("sssp", small_powerlaw)
         part, mesh = self._engine_parts(g, partitioner)
         eng = DistributedEngine(sssp_program(), mesh)
-        out, _ = eng.run(g, part, source=0)
+        out, _ = eng.run(eng.load(g, part), source=0)
         np.testing.assert_allclose(out, reference_sssp(g, 0), rtol=1e-5)
 
     def test_pagerank_matches_reference(self, small_powerlaw, partitioner):
         g = prepare_graph("pagerank", small_powerlaw)
         part, mesh = self._engine_parts(g, partitioner)
         eng = DistributedEngine(pagerank_program(), mesh)
-        out, _ = eng.run(g, part, max_iterations=200)
+        out, _ = eng.run(eng.load(g, part), max_iterations=200)
         np.testing.assert_allclose(out, reference_pagerank(g), atol=1e-3)
 
     def test_bf16_compressed_exchange(self, small_powerlaw, partitioner):
@@ -84,8 +85,89 @@ class TestDistributedEngine:
         g = prepare_graph("pagerank", small_powerlaw)
         part, mesh = self._engine_parts(g, partitioner)
         eng = DistributedEngine(pagerank_program(), mesh, comm_dtype=jnp.bfloat16)
-        out, _ = eng.run(g, part, max_iterations=200)
+        out, _ = eng.run(eng.load(g, part), max_iterations=200)
         np.testing.assert_allclose(out, reference_pagerank(g), atol=5e-2)
+
+
+REFERENCES = {"bfs": reference_bfs, "sssp": reference_sssp, "pagerank": reference_pagerank}
+
+
+def _resident(algorithm, monkeypatch, roots):
+    """Load one seeded R-MAT graph once and answer a query from each root,
+    counting the builds of the sharded graph and the traces of the step."""
+    import dataclasses
+
+    from repro.core.partition import partition_by_name
+
+    g = rmat(400, 3200, seed=11)
+    if algorithm == "sssp":
+        # whole weights keep every path length exact in float32, so the
+        # answer can equal the float64 reference exactly
+        w = np.random.default_rng(11).integers(1, 9, g.num_edges).astype(np.float32)
+        g = HostGraph(g.num_nodes, g.src, g.dst, w, g.name)
+    g = prepare_graph(algorithm, g)
+    builds, traces = [], []
+    real_build = ShardedVertexGraph.build
+    monkeypatch.setattr(ShardedVertexGraph, "build",
+                        staticmethod(lambda *a: builds.append(1) or real_build(*a)))
+    program = {"bfs": bfs_program, "sssp": sssp_program, "pagerank": pagerank_program}[algorithm]()
+    process = program.process
+    program = dataclasses.replace(program, process=lambda *a: traces.append(1) or process(*a))
+    eng = DistributedEngine(program, make_engines_mesh())
+    sg = eng.load(g, partition_by_name("powerlaw", g.src, g.dst, g.num_nodes, 1))
+    answers = [eng.run(sg, source=r) for r in roots]
+    return g, answers, len(builds), len(traces)
+
+
+@pytest.mark.parametrize("algorithm", ["bfs", "sssp", "pagerank"])
+def test_resident_engine_answers_query_after_query(algorithm, monkeypatch):
+    """`load` once, then `run` per query: every answer equals the reference,
+    the sharded graph is built once and the step is traced once."""
+    roots = [0, 7, 123] if algorithm != "pagerank" else [0, 0]
+    g, answers, builds, traces = _resident(algorithm, monkeypatch, roots)
+    for r, (out, it) in zip(roots, answers):
+        if algorithm == "pagerank":
+            np.testing.assert_allclose(out, reference_pagerank(g), atol=1e-3)
+        else:
+            np.testing.assert_array_equal(np.asarray(out, np.float64), REFERENCES[algorithm](g, r))
+        assert it >= 1
+    assert builds == 1
+    assert traces == 1
+
+
+def test_spans_of_load_and_run():
+    """`dist.load` carries the build's host time and the bytes placed on the
+    mesh; each `dist.run` its waits, host time, dispatches, iterations and
+    exchanged bytes.  With tracing off nothing is recorded."""
+    from repro import obs
+    from repro.core.partition import partition_by_name
+
+    g = rmat(300, 2400, seed=7)
+    part = partition_by_name("powerlaw", g.src, g.dst, g.num_nodes, 1)
+    eng = DistributedEngine(bfs_program(), make_engines_mesh())
+    tracer = obs.get_tracer()
+    tracer.reset()
+    sg = eng.load(g, part)
+    eng.run(sg, source=0)
+    assert tracer.spans() == []
+
+    obs.enable_tracing()
+    try:
+        sg = eng.load(g, part)
+        answers = [eng.run(sg, source=r) for r in (0, 5)]
+    finally:
+        obs.disable_tracing()
+    spans = tracer.spans()
+    tracer.reset()
+    loads = [s for s in spans if s.name == "dist.load"]
+    runs = [s for s in spans if s.name == "dist.run"]
+    assert len(loads) == 1 and len(runs) == 2
+    placed = sum(np.asarray(a).nbytes for a in (sg.src_slot, sg.dst_key, sg.weight, sg.valid))
+    assert loads[0].args["host_ns"] > 0 and loads[0].args["h2d_bytes"] == placed
+    for sp, (_, it) in zip(runs, answers):
+        assert sp.args["iterations"] == it == sp.args["dispatches"]
+        assert sp.args["wait_ns"] > 0 and sp.args["host_ns"] > 0
+        assert sp.args["exchange_bytes"] == it * sg.num_devices * sg.n_local * 4
 
 
 class TestRooflineMachinery:

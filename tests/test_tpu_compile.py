@@ -2,7 +2,7 @@
 
 Each test lowers one program of the sweep's jax path (or a Pallas kernel) at
 the shapes the chip runs and compiles it for one chip of a described
-`v5e:2x2` topology: nothing runs, so these say nothing about results or
+`v5e:2x2` topology (the distributed step for all four): nothing runs, so these say nothing about results or
 times, but they catch what the chip's compiler refuses (misaligned blocks,
 memory it cannot fit) without a chip.  The topology is described inside a
 fixture, never at import: only one process at a time may load the TPU
@@ -12,6 +12,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -38,7 +39,7 @@ FLOWS = ROUTERS * (ROUTERS - 1)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -52,9 +53,14 @@ def one_chip():
     was_enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _shapes(sharding, *specs):
@@ -84,6 +90,35 @@ def test_traced_step_at_soc_pokec_quarter_scale(one_chip, program):
     compiled = _compile(traced_step, program(), (src, dst, valid, weight), props, active, aux)
     # The edge arrays are arguments of the program, not constants in it.
     assert compiled.memory_analysis().argument_size_in_bytes >= EDGES * 9
+
+
+@pytest.mark.parametrize("program", [pagerank_program, bfs_program], ids=["pagerank", "bfs"])
+def test_distributed_step_on_the_2x2_mesh(topo, program):
+    """`DistributedEngine`'s step for soc-pokec at scale 0.25 dealt over the
+    four chips (Algorithm 2's cap: ceil(1.05 · |E| / 4) edge slots a chip),
+    with its all_to_all exchange and the all-reduce of its stop test."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.graph.distributed import DistributedEngine
+
+    engines, n_local, e_local = 4, (NODES - 1) // 4, -(-EDGES * 105 // 400)
+    mesh = Mesh(np.asarray(topo.devices), ("engines",))
+    sharded = NamedSharding(mesh, P("engines"))
+    props, active, src_slot, dst_key, weight, valid = _shapes(
+        sharded,
+        ((engines, n_local + 1), jnp.float32),
+        ((engines, n_local + 1), jnp.bool_),
+        ((engines, e_local), jnp.int32),
+        ((engines, e_local), jnp.int32),
+        ((engines, e_local), jnp.float32),
+        ((engines, e_local), jnp.bool_),
+    )
+    aux = {"base": jax.ShapeDtypeStruct((), jnp.float32, sharding=NamedSharding(mesh, P()))}
+    if program is bfs_program:
+        aux = {}
+    step = DistributedEngine(program(), mesh)._step
+    text = _compile(step, props, active, src_slot, dst_key, weight, valid, aux).as_text()
+    assert "all-to-all(" in text and "all-reduce(" in text
 
 
 def test_simulate_batch_contraction(one_chip):
